@@ -56,10 +56,10 @@ def _decode_step_time(cfg, params, layout, batch, prompt_len, steps):
     """Mean decode-only step time with ``batch`` co-resident sequences.
 
     The pool is sized to the workload's residency (as a deployment sizes
-    its HBM pool): an oversized pool costs nothing on TPU (donated
-    buffers update in place through the layer scan) but XLA-CPU copies
-    scan-carried buffers per layer, which would charge the paged layout
-    for capacity it isn't using."""
+    its HBM pool).  An oversized pool is not free on any backend: the
+    layer scan builds its stacked cache output as a second full pool
+    (on a TPU in temporary HBM, next to the donated input), so a larger
+    pool would charge the paged layout for capacity it isn't using."""
     pages = -(-(prompt_len + steps + 8) // PAGE) * batch
     eng = _engine(cfg, params, layout, max_slots=batch, num_pages=pages,
                   max_context=128)

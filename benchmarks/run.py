@@ -19,6 +19,8 @@ import json
 import time
 from pathlib import Path
 
+from repro.launch.compile_cache import use_compile_cache
+
 
 def _emit(out_dir: Path, name: str, payload: dict) -> None:
     out_dir.mkdir(parents=True, exist_ok=True)
@@ -38,6 +40,7 @@ def main() -> int:
     ap.add_argument("--smoke", action="store_true",
                     help="reduced sweeps for CI smoke runs")
     args = ap.parse_args()
+    print(f"# compile cache: {use_compile_cache()}", flush=True)
     want = None if args.only == "all" else set(args.only.split(","))
     out_dir = Path(args.out_dir)
 

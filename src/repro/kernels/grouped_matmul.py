@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 
 def _kernel(counts_ref, x_ref, w_ref, o_ref, acc_ref, *,
             blk_c: int):
@@ -80,7 +78,7 @@ def grouped_matmul(x: jax.Array, w: jax.Array, counts: jax.Array, *,
                                lambda e_, i, j, k_: (e_, i, j)),
         out_shape=jax.ShapeDtypeStruct((e, c, f), x.dtype),
         scratch_shapes=[pltpu.VMEM((blk_c, blk_f), jnp.float32)],
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

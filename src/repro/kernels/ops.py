@@ -1,10 +1,10 @@
 """jit'd public wrappers around the Pallas kernels.
 
 Handles model-layout <-> kernel-layout transposes, pads head dims to the
-TPU lane width (128) and sublane minimum (8), and auto-selects
-interpret mode off-TPU (this container is CPU: kernels execute their
-bodies in Python via interpret=True; on a real TPU the same code lowers
-to Mosaic).
+TPU lane width (128) and sublane minimum (8), and picks how a kernel
+runs from the default backend: compiled through Mosaic on TPU,
+interpreted (``interpret=True``) on the CPU backend the tests use, and
+refused on any other backend.
 """
 from __future__ import annotations
 
@@ -22,8 +22,21 @@ LANE = 128
 SUBLANE = 8
 
 
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def interpret_mode(interpret: bool | None = None) -> bool:
+    """Resolve a wrapper's ``interpret`` argument: an explicit value wins;
+    otherwise TPU compiles the kernel and CPU interprets it.  Any other
+    backend raises, so a kernel never silently runs interpreted on an
+    accelerator it was not written for."""
+    if interpret is not None:
+        return interpret
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas TPU kernels cannot run on the {backend!r} backend "
+        "(compiled on tpu, interpreted on cpu)")
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
@@ -39,8 +52,7 @@ def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
 def flash_attention(q, k, v, *, causal=True, window=-1,
                     blk_q=128, blk_k=128, interpret=None):
     """Model layout: q (B,S,H,dh); k,v (B,T,Hkv,dh) -> (B,S,H,dh)."""
-    if interpret is None:
-        interpret = not on_tpu()
+    interpret = interpret_mode(interpret)
     b, s, h, dh = q.shape
     t = k.shape[1]
     qt = _pad_to(jnp.moveaxis(q, 2, 1), 3, LANE)       # (B,H,S,dh')
@@ -62,8 +74,7 @@ def decode_attention(q, cache_k, cache_v, kpos, q_pos, *, window=-1,
                      blk_k=128, interpret=None):
     """Model layout: q (B,1,H,dh); cache k/v (B,T,Hkv,dh); kpos (B,T);
     q_pos (B,) -> (B,1,H,dh)."""
-    if interpret is None:
-        interpret = not on_tpu()
+    interpret = interpret_mode(interpret)
     b, _, h, dh = q.shape
     t = cache_k.shape[1]
     hkv = cache_k.shape[2]
@@ -96,8 +107,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
     order, -1 = unmapped tail; ctx_lens (B,) int32 valid cached tokens.
     Returns (B, 1, H, dh).
     """
-    if interpret is None:
-        interpret = not on_tpu()
+    interpret = interpret_mode(interpret)
     b, _, h, dh = q.shape
     hkv = k_pages.shape[2]
     g = h // hkv
@@ -114,8 +124,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
 
 def grouped_matmul(x, w, counts, *, interpret=None):
     """x (E,C,d) @ w (E,d,f) with per-expert row counts."""
-    if interpret is None:
-        interpret = not on_tpu()
+    interpret = interpret_mode(interpret)
     e, c, d = x.shape
     f = w.shape[2]
     xp = _pad_to(_pad_to(x, 1, SUBLANE), 2, LANE)
@@ -127,8 +136,7 @@ def grouped_matmul(x, w, counts, *, interpret=None):
 def ssm_scan(q, k, v, log_a, h0, *, chunk=128, interpret=None):
     """Model layout: q,k (B,T,H,dk); v (B,T,H,dv); log_a (B,T,H);
     h0 (B,H,dk,dv) -> (y (B,T,H,dv), hT)."""
-    if interpret is None:
-        interpret = not on_tpu()
+    interpret = interpret_mode(interpret)
     b, t, h, dk = q.shape
     dv = v.shape[3]
     qt = jnp.moveaxis(q, 2, 1)

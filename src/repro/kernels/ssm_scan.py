@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 
 def _kernel(q_ref, k_ref, v_ref, la_ref, h0_ref, y_ref, hT_ref, h_ref, *,
             chunk: int):
@@ -96,7 +94,7 @@ def ssm_scan(q: jax.Array, k: jax.Array, v: jax.Array, log_a: jax.Array,
             jax.ShapeDtypeStruct((b, h, dk, dv), jnp.float32),
         ),
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, log_a, h0)

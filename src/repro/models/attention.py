@@ -41,10 +41,10 @@ NEG_INF = -1e30
 def attn_defs(cfg: ModelConfig, cross: bool = False) -> dict:
     d, h, hkv, dh = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     defs = {
-        "wq": P((d, h, dh), ("embed", "heads", None)),
-        "wk": P((d, hkv, dh), ("embed", "kv_heads", None)),
-        "wv": P((d, hkv, dh), ("embed", "kv_heads", None)),
-        "wo": P((h, dh, d), ("heads", None, "embed")),
+        "wq": P((d, h, dh), ("embed", "heads", None), fan_in=d),
+        "wk": P((d, hkv, dh), ("embed", "kv_heads", None), fan_in=d),
+        "wv": P((d, hkv, dh), ("embed", "kv_heads", None), fan_in=d),
+        "wo": P((h, dh, d), ("heads", None, "embed"), fan_in=h * dh),
     }
     if cfg.qk_norm and not cross:
         defs["q_scale"] = P((dh,), (None,), init="ones", dtype="float32")

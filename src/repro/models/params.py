@@ -12,7 +12,7 @@ this module is mesh-agnostic.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from typing import Any, Optional
 
 import jax
@@ -27,6 +27,8 @@ class P:
     init: str = "normal"            # normal | zeros | ones
     scale: float = 1.0              # stddev multiplier for 'normal'
     dtype: Optional[str] = None     # override model dtype (e.g. f32 norms)
+    fan_in: Optional[int] = None    # inputs per output where the
+    #                                 last-but-one dim is not it
 
     def __post_init__(self):
         assert len(self.shape) == len(self.axes), (self.shape, self.axes)
@@ -38,8 +40,8 @@ Tree = Any  # nested dict of P / arrays / specs
 def stack(defs: Tree, *dims: int) -> Tree:
     """Prepend layer-stack dims (replicated axes) to every P in the tree."""
     def go(p: P) -> P:
-        return P(tuple(dims) + p.shape, (None,) * len(dims) + p.axes,
-                 p.init, p.scale, p.dtype)
+        return replace(p, shape=tuple(dims) + p.shape,
+                       axes=(None,) * len(dims) + p.axes)
     return jax.tree.map(go, defs, is_leaf=lambda x: isinstance(x, P))
 
 
@@ -50,7 +52,7 @@ def _init_one(p: P, key: jax.Array, dtype: jnp.dtype) -> jax.Array:
     if p.init == "ones":
         return jnp.ones(p.shape, dt)
     # fan-in scaled normal on the last-but-one "input" dim heuristic:
-    fan_in = p.shape[-2] if len(p.shape) >= 2 else p.shape[-1]
+    fan_in = p.fan_in or (p.shape[-2] if len(p.shape) >= 2 else p.shape[-1])
     std = p.scale / np.sqrt(max(fan_in, 1))
     return (jax.random.normal(key, p.shape, jnp.float32) * std).astype(dt)
 

@@ -6,11 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _jax_caps import HAVE_PALLAS_API, PALLAS_SKIP_REASON
 from repro.kernels import ops, ref
-
-pytestmark = pytest.mark.skipif(not HAVE_PALLAS_API,
-                                reason=PALLAS_SKIP_REASON)
 
 
 def _assert_close(a, b, dtype, tol_f32=2e-5, tol_bf16=2e-2):
@@ -18,6 +14,21 @@ def _assert_close(a, b, dtype, tol_f32=2e-5, tol_bf16=2e-2):
     np.testing.assert_allclose(np.asarray(a, np.float32),
                                np.asarray(b, np.float32),
                                atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("backend,want", [("tpu", False), ("cpu", True),
+                                          ("gpu", None)])
+def test_interpret_mode_follows_the_backend(monkeypatch, backend, want):
+    """Compiled on TPU, interpreted on the CPU the tests use, refused
+    elsewhere; an explicit argument always wins."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert ops.interpret_mode(True) is True
+    assert ops.interpret_mode(False) is False
+    if want is None:
+        with pytest.raises(RuntimeError, match=backend):
+            ops.interpret_mode()
+    else:
+        assert ops.interpret_mode() is want
 
 
 # ---------------------------------------------------------------------------

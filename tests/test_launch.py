@@ -5,12 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _jax_caps import HAVE_MESH_API, MESH_SKIP_REASON
-
-# only the compile-and-run tests need the mesh API; the parsing and
-# extrapolation helpers below run on any JAX
-needs_mesh = pytest.mark.skipif(not HAVE_MESH_API, reason=MESH_SKIP_REASON)
-
 from repro import models
 from repro.configs import get_config, get_smoke
 from repro.configs.base import ShapeConfig
@@ -25,7 +19,6 @@ from repro.launch.train import (AdamWConfig, TrainPlan, abstract_state,
 from repro.optim.adamw import adamw_init
 
 
-@needs_mesh
 def test_train_step_runs_and_learns():
     cfg = get_config("tiny-agent")
     mesh = make_mesh((1, 1), ("data", "model"))
@@ -46,7 +39,6 @@ def test_train_step_runs_and_learns():
     assert losses[-1] < losses[0] - 0.3      # memorizes a fixed batch
 
 
-@needs_mesh
 def test_serve_step_matches_models_decode():
     cfg = get_config("tiny-agent")
     mesh = make_mesh((1, 1), ("data", "model"))
@@ -65,7 +57,6 @@ def test_serve_step_matches_models_decode():
                                atol=1e-4)
 
 
-@needs_mesh
 def test_prefill_step_runs():
     cfg = get_config("tiny-agent")
     mesh = make_mesh((1, 1), ("data", "model"))
@@ -80,7 +71,6 @@ def test_prefill_step_runs():
         assert int(cache["pos"][0]) == 32
 
 
-@needs_mesh
 def test_opt_pspecs_structure_matches_state():
     cfg = get_smoke("llama3-405b")
     mesh = make_mesh((1, 1), ("data", "model"))

@@ -171,3 +171,21 @@ def test_moe_groupwise_matches_global_dispatch():
     # generous capacity => no drops on either path => identical routing
     np.testing.assert_allclose(np.asarray(y_grouped), np.asarray(y_global),
                                atol=2e-4, rtol=2e-4)
+
+
+def test_attention_projections_init_at_their_fan_in():
+    """wq/wk/wv read d_model inputs and wo reads heads x d_head, so each
+    draws at std 1/sqrt(fan_in); at the head count's fan-in the scores'
+    std is ~100x too large and attention degenerates to an argmax."""
+    cfg = get_config("tiny-agent").replace(d_model=256, n_heads=4,
+                                           n_kv_heads=2, d_head=64)
+    params = models.init(cfg, jax.random.key(0))
+    fan_in = {"wq": 256, "wk": 256, "wv": 256, "wo": 4 * 64}
+    seen = set()
+    for path, w in jax.tree_util.tree_leaves_with_path(params):
+        name = getattr(path[-1], "key", None)
+        if name in fan_in:
+            seen.add(name)
+            std = float(np.asarray(w, np.float32).std())
+            assert std == pytest.approx(fan_in[name] ** -0.5, rel=0.05), name
+    assert seen == set(fan_in)

@@ -227,9 +227,6 @@ def test_block_tables_ragged_rows_pad_with_minus_one():
 
 def test_paged_attention_matches_contiguous_through_allocator():
     jax = pytest.importorskip("jax")
-    from _jax_caps import HAVE_PALLAS_API, PALLAS_SKIP_REASON
-    if not HAVE_PALLAS_API:
-        pytest.skip(PALLAS_SKIP_REASON)
     import jax.numpy as jnp
     import numpy as np
     from repro.kernels import ops
