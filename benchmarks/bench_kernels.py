@@ -1,28 +1,22 @@
-"""Kernel microbench: correctness deltas vs oracle + analytic kernel
-roofline (VMEM working set, arithmetic intensity, projected v5e time).
+"""Kernel microbench: correctness deltas vs oracle, VMEM working set and
+arithmetic intensity.
 
-This container has no TPU: wall-clock numbers here would measure the
-Python interpreter, not the kernel.  What we CAN report honestly per
-kernel/shape is (a) max |err| vs the pure-jnp oracle in interpret mode,
-(b) the BlockSpec working set vs the 16 MB/core VMEM budget, and (c)
-the roofline-projected v5e time from exact FLOP/byte counts.
+Kernels run in interpret mode here, so wall-clock numbers would measure
+the Python interpreter, not the kernel.  What this reports per
+kernel/shape is (a) max |err| vs the pure-jnp oracle, (b) the BlockSpec
+working set vs the 16 MB/core VMEM budget, and (c) the FLOPs per byte
+of HBM traffic.  Kernel time on the chip is the chip benchmark's
+(``chipbench/``, e.g. ``paged_decode_attention_roofline``).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from benchmarks.common import Report
 from repro.kernels import ops, ref
 
-PEAK = 197e12
-HBM = 819e9
 VMEM = 16 * 2**20
-
-
-def _proj(flops, bytes_):
-    return max(flops / PEAK, bytes_ / HBM)
 
 
 def flash_attention_row(rep, b, s, h, hkv, dh, blk=128):
@@ -42,8 +36,7 @@ def flash_attention_row(rep, b, s, h, hkv, dh, blk=128):
     rep.add(f"kernels.flash_attention.b{b}s{s}h{h}kv{hkv}d{dh}",
             max_err=f"{err:.2e}",
             vmem_kb=vmem // 1024, vmem_ok=vmem < VMEM,
-            intensity=f"{flops/bytes_:.0f}",
-            v5e_us=f"{_proj(flops, bytes_)*1e6:.1f}")
+            intensity=f"{flops/bytes_:.0f}")
 
 
 def decode_attention_row(rep, b, h, hkv, dh, t, blk=128):
@@ -65,8 +58,7 @@ def decode_attention_row(rep, b, h, hkv, dh, t, blk=128):
     rep.add(f"kernels.decode_attention.b{b}h{h}kv{hkv}d{dh}t{t}",
             max_err=f"{err:.2e}",
             vmem_kb=vmem // 1024, vmem_ok=vmem < VMEM,
-            intensity=f"{flops/bytes_:.1f}",
-            v5e_us=f"{_proj(flops, bytes_)*1e6:.1f}")
+            intensity=f"{flops/bytes_:.1f}")
 
 
 def paged_decode_attention_row(rep, b, h, hkv, dh, page, per_seq, shared):
@@ -93,18 +85,15 @@ def paged_decode_attention_row(rep, b, h, hkv, dh, page, per_seq, shared):
                                           k_pages, v_pages, bt, ctx)
     err = float(jnp.abs(out.reshape(b, hkv, g, dh) - want).max())
     t = per_seq * page
-    flops = 4.0 * b * h * t * dh
     # unique pages read once: shared prefix pages are not re-read per row
     uniq_toks = n * page
-    bytes_ = 2 * uniq_toks * hkv * dh * 2 * 2        # K+V, bf16
     dhp = max(dh, 128)
     vmem = (max(g, 8) * dhp + 2 * page * dhp) * 4
     rep.add(f"kernels.paged_decode_attention."
             f"b{b}h{h}kv{hkv}d{dh}p{page}x{per_seq}s{shared}",
             max_err=f"{err:.2e}",
             vmem_kb=vmem // 1024, vmem_ok=vmem < VMEM,
-            shared_read_saving=f"{1 - uniq_toks/(b*t):.0%}",
-            v5e_us=f"{_proj(flops, bytes_)*1e6:.1f}")
+            shared_read_saving=f"{1 - uniq_toks/(b*t):.0%}")
 
 
 def grouped_matmul_row(rep, e, c, d, f):
@@ -115,14 +104,10 @@ def grouped_matmul_row(rep, e, c, d, f):
     out = ops.grouped_matmul(x, w, counts, interpret=True)
     want = ref.grouped_matmul_ref(x, w, counts)
     err = float(jnp.abs(out - want).max())
-    live = e // 2
-    flops = 2.0 * live * c * d * f                  # empty experts skipped
-    bytes_ = (live * c * d + live * d * f + live * c * f) * 2
     vmem = (128 * 128 * 3 + 128 * 128) * 4
     rep.add(f"kernels.grouped_matmul.e{e}c{c}d{d}f{f}",
             max_err=f"{err:.2e}", vmem_kb=vmem // 1024, vmem_ok=True,
-            skip_saving=f"{e//2}/{e} experts idle",
-            v5e_us=f"{_proj(flops, bytes_)*1e6:.1f}")
+            skip_saving=f"{e//2}/{e} experts idle")
 
 
 def ssm_scan_row(rep, b, h, t, dk, dv, chunk=128):
@@ -139,17 +124,14 @@ def ssm_scan_row(rep, b, h, t, dk, dv, chunk=128):
                               jnp.moveaxis(la, 2, 1)[..., None], h0)
     err = float(jnp.abs(y - jnp.moveaxis(yr, 1, 2)).max())
     c = min(chunk, t)
-    flops = b * h * t * (4 * dk * dv + 2 * c * dk + 2 * c * dv)
-    bytes_ = (q.size + k.size + v.size + y.size) * 2
     vmem = (3 * c * max(dk, 128) + c * c + dk * dv) * 4
     rep.add(f"kernels.ssm_scan.b{b}h{h}t{t}dk{dk}dv{dv}",
             max_err=f"{err:.2e}",
-            vmem_kb=vmem // 1024, vmem_ok=vmem < VMEM,
-            v5e_us=f"{_proj(flops, bytes_)*1e6:.1f}")
+            vmem_kb=vmem // 1024, vmem_ok=vmem < VMEM)
 
 
 def main(report: Report | None = None) -> Report:
-    rep = report or Report("kernels: oracle deltas + v5e roofline")
+    rep = report or Report("kernels: oracle deltas + VMEM working sets")
     flash_attention_row(rep, 1, 512, 8, 2, 128)
     flash_attention_row(rep, 2, 256, 4, 4, 64)
     decode_attention_row(rep, 4, 8, 2, 128, 1024)
@@ -159,8 +141,7 @@ def main(report: Report | None = None) -> Report:
     grouped_matmul_row(rep, 8, 128, 256, 512)
     ssm_scan_row(rep, 1, 4, 256, 64, 64)
     rep.note("kernels: interpret-mode correctness vs ref.py oracle; "
-             "VMEM working sets within the 16MB/core budget; v5e time "
-             "is the analytic roofline projection (no TPU in container)")
+             "VMEM working sets within the 16MB/core budget")
     return rep
 
 

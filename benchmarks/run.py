@@ -1,5 +1,5 @@
 """Benchmark orchestrator — one section per paper figure/table plus the
-kernel microbench and (if dry-run artifacts exist) the roofline tables.
+kernel microbench.
 
     PYTHONPATH=src python -m benchmarks.run [--only fig3,fig6,...]
                                             [--out-dir artifacts/bench]
@@ -34,7 +34,7 @@ def main() -> int:
     ap.add_argument("--only", default="all",
                     help="comma list: fig3,fig6,fig7,prefix,workflow,"
                          "toolcalls,disagg,tenancy,trace,kernels,paged,"
-                         "mixed,calibrate,roofline")
+                         "mixed,calibrate")
     ap.add_argument("--out-dir", default="artifacts/bench",
                     help="directory for BENCH_*.json summaries")
     ap.add_argument("--smoke", action="store_true",
@@ -47,8 +47,7 @@ def main() -> int:
     summary: dict[str, dict] = {}
     names = [n for n in ("fig3", "fig6", "fig7", "prefix", "workflow",
                          "toolcalls", "disagg", "tenancy", "trace",
-                         "kernels", "paged", "mixed", "calibrate",
-                         "roofline")
+                         "kernels", "paged", "mixed", "calibrate")
              if want is None or n in want]
     for name in names:
         t0 = time.time()
@@ -95,13 +94,6 @@ def main() -> int:
             from benchmarks import calibrate
             report = calibrate.main(smoke=args.smoke,
                                     out_dir=str(out_dir))
-        elif name == "roofline":
-            from benchmarks import roofline
-            if Path("artifacts/dryrun").exists():
-                roofline.main()
-            else:
-                print("# no artifacts/dryrun — run "
-                      "`python -m repro.launch.dryrun` first")
         elapsed = time.time() - t0
         if report is not None:
             print(report.render())

@@ -1,0 +1,8 @@
+"""Engine: mean over the window's mixed steps of ``engine.step`` less
+its ``engine.sync`` child (Tracer spans): the host's part of a step."""
+from chipbench import phases
+
+
+def read(run):
+    spans = getattr(run, "spans", None)
+    return phases.host_ms(spans, "mixed") if spans else None

@@ -40,10 +40,22 @@ load it at ``chrome://tracing`` or https://ui.perfetto.dev): complete
 flow ("s"/"f") events drawing each causal action→span link.
 ``tools/trace_report.py`` walks the exported JSON alone and reprints
 the DAG critical path with the dominant segment per stage.
+
+* ``Tracer.phase`` — step-phase spans (category ``step``) for a live
+  engine: each also opens a ``jax.profiler.TraceAnnotation`` of the same
+  name, so under ``jax.profiler.trace`` the phase sits in the profile's
+  host plane beside the device's operations.  ``Tracer.anchor`` states
+  the offset from the tracer's clock to the host's ``CLOCK_REALTIME``,
+  the clock the profiler stamps host events with (an ``.xplane.pb``
+  stores them relative to its ``profile_start_time``), so spans and
+  request segments can be laid on the device timeline.  The sim's
+  tracers keep their virtual clock and are never anchored.
 """
 from __future__ import annotations
 
+import contextlib
 import json
+import time
 import zlib
 from dataclasses import dataclass, field
 from pathlib import Path
@@ -56,6 +68,10 @@ from repro.core.metrics import MetricBus, Ring
 SEGMENTS = ("queue_wait", "throttle_hold", "handoff_wait",
             "prefill", "decode")
 
+# what ``Tracer.phase`` returns when there is nothing to record: one
+# shared context that enters to None, builds nothing and reads no clock
+NO_PHASE = contextlib.nullcontext()
+
 
 @dataclass
 class Span:
@@ -65,7 +81,7 @@ class Span:
 
     span_id: int
     name: str
-    cat: str                       # task | stage | request | segment | kv
+    cat: str                 # task | stage | request | segment | kv | step
     trace_id: str
     t0: float
     t1: Optional[float] = None
@@ -110,6 +126,10 @@ class Tracer(ControlSurface):
         self._next_id = 0
         self.spans_total = 0
         self.spans_dropped = 0
+        # the clock spans are stamped on, and (once anchored) the
+        # CLOCK_REALTIME seconds at its zero
+        self.clock_label = "virtual-seconds"
+        self.origin_unix_s: Optional[float] = None
 
     def _surface_now(self) -> float:
         return self.clock()
@@ -209,6 +229,40 @@ class Tracer(ControlSurface):
             self.collector.observe(f"request.{span.name}", span.dur,
                                    span.t1)
 
+    # -- step phases (live engines) ------------------------------------------
+    def anchor(self, clock_label: str) -> float:
+        """Name this tracer's clock and take, once, the offset from it to
+        the host's ``CLOCK_REALTIME``: a span at ``t`` began at
+        ``t + origin_unix_s`` Unix seconds, which is
+        ``(t + origin_unix_s) * 1e9 - profile_start_time`` in an
+        ``.xplane.pb``'s host plane.  Returns the offset."""
+        self.clock_label = clock_label
+        self.origin_unix_s = time.time() - self.clock()
+        return self.origin_unix_s
+
+    def phase(self, name: str, parent: Optional[Span] = None, **attrs):
+        """Context manager for one phase of an engine step: a ``step``
+        span (trace id: the parent's, else ``attrs["engine"]``) inside a
+        ``jax.profiler.TraceAnnotation`` of the same name.  Enters to the
+        span, whose ``attrs`` the caller may fill before it closes.
+        Disabled, it is the shared ``NO_PHASE`` and enters to None."""
+        if not self.enabled:
+            return NO_PHASE
+        return self._phase(name, parent, attrs)
+
+    @contextlib.contextmanager
+    def _phase(self, name: str, parent: Optional[Span], attrs: dict):
+        from jax.profiler import TraceAnnotation
+        trace_id = (parent.trace_id if parent is not None
+                    else attrs.get("engine", self.name))
+        with TraceAnnotation(name):
+            sp = self.begin(name, trace_id, cat="step", parent=parent,
+                            **attrs)
+            try:
+                yield sp
+            finally:
+                self.end(sp)
+
     # -- task roots ---------------------------------------------------------
     def begin_task(self, task_id: str, tenant: str = "default",
                    t: Optional[float] = None, **attrs) -> Optional[Span]:
@@ -294,10 +348,14 @@ class Tracer(ControlSurface):
         for track, p in tracks.items():
             events.append({"name": "process_name", "ph": "M", "ts": 0,
                            "pid": p, "tid": 0, "args": {"name": track}})
+        other = {"clock": self.clock_label, "spans": len(spans),
+                 "actions": len(actions), "links": len(links)}
+        if self.origin_unix_s is not None:
+            # ts / 1e6 + origin_unix_s: CLOCK_REALTIME, the profiler's
+            # host clock
+            other["origin_unix_s"] = self.origin_unix_s
         doc = {"traceEvents": events, "displayTimeUnit": "ms",
-               "otherData": {"clock": "virtual-seconds",
-                             "spans": len(spans), "actions": len(actions),
-                             "links": len(links)}}
+               "otherData": other}
         if path is not None:
             Path(path).write_text(json.dumps(doc, indent=1) + "\n")
         return doc

@@ -12,9 +12,7 @@ Per cell this produces artifacts/dryrun/<arch>.<shape>.<mesh>.json with:
   * compiled.cost_analysis() FLOPs / bytes accessed,
   * compiled.memory_analysis() per-device byte breakdown,
   * collective bytes by op kind, parsed from the optimized HLO,
-  * MODEL_FLOPS (6·N·D train / 2·N·D forward, N_active for MoE),
-and EXPERIMENTS.md §Dry-run / §Roofline are rendered from these files by
-benchmarks/roofline.py.
+  * MODEL_FLOPS (6·N·D train / 2·N·D forward, N_active for MoE).
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun                    # all cells

@@ -34,11 +34,22 @@ never stalls the decode batch.  Slot state surgery uses
 serving/cache_utils (ring) or the transformer's
 paged_extract/paged_insert bridge (paged); KV migration uses
 serving/kv_transfer in both layouts.
+
+Observability: with an enabled ``core.trace.Tracer`` attached
+(``attach_tracer``), every ``step()`` records an ``engine.step`` span
+whose children tile it — ``engine.plan`` (the scheduler),
+``engine.inputs`` (block tables, token buffers, the key split and their
+host-to-device copies), ``engine.dispatch`` (the jitted call until it
+returns), ``engine.sync`` (the device-to-host read of the sampled
+tokens) and ``engine.apply`` (bookkeeping) — each also a profiler
+``TraceAnnotation``.  ``traces`` counts each jitted function's traces
+(a compile or a compile-cache load).
 """
 from __future__ import annotations
 
 import time
-from functools import partial
+from collections import Counter
+from functools import wraps
 
 import jax
 import jax.numpy as jnp
@@ -47,6 +58,7 @@ import numpy as np
 from repro import models
 from repro.configs.base import ModelConfig
 from repro.core.knobs import KnobSpec
+from repro.core.trace import NO_PHASE
 from repro.core.types import Request, RequestState
 from repro.serving import cache_utils, sampler
 from repro.serving.engine_base import EngineCore
@@ -81,12 +93,24 @@ class Engine(EngineCore):
         self._cache_layout = cache_layout
         self._last_token = np.zeros((sched_cfg.max_slots,), np.int32)
         self._build_cache()
+        # traces of each jitted function, by name: a Python side effect
+        # of the traced body runs per TRACE, not per call
+        self.traces: Counter = Counter()
+
+        def jit(donate):
+            def wrap(fn):
+                @wraps(fn)
+                def counted(*args):
+                    self.traces[fn.__name__] += 1
+                    return fn(*args)
+                return jax.jit(counted, donate_argnums=donate)
+            return wrap
 
         # every step consumes the previous cache and returns the next one,
         # so the cache buffers are donated: the in-place update XLA can do
         # then is what makes the shared page pool (one big buffer per
         # layer, scatter-written every step) cost the same as the ring
-        @partial(jax.jit, donate_argnums=(2,))
+        @jit(donate=(2,))
         def _prefill(params, tokens, cache, key, temperature):
             # forward + first-token sample in one program: logits are
             # consumed on-device, only the token id comes back
@@ -94,13 +118,13 @@ class Engine(EngineCore):
             tok = sampler.sample(logits, key, temperature)
             return tok, cache
 
-        @partial(jax.jit, donate_argnums=(2,))
+        @jit(donate=(2,))
         def _decode(params, tokens, cache, key, temperature):
             logits, cache = models.decode_step(params, cfg, tokens, cache)
             tok = sampler.sample(logits, key, temperature)
             return tok, cache
 
-        @partial(jax.jit, donate_argnums=(2,))
+        @jit(donate=(2,))
         def _prefill_paged(params, tokens, cache, tables, start, slot, key,
                            temperature):
             logits, cache = models.prefill_paged(params, cfg, tokens, cache,
@@ -108,7 +132,7 @@ class Engine(EngineCore):
             tok = sampler.sample(logits, key, temperature)
             return tok, cache
 
-        @partial(jax.jit, donate_argnums=(2,))
+        @jit(donate=(2,))
         def _decode_paged(params, tokens, cache, tables, key, temperature):
             logits, cache = models.decode_step(params, cfg, tokens, cache,
                                                tables)
@@ -125,12 +149,9 @@ class Engine(EngineCore):
         # sequences' own pages); the prefill chunk then attends into its
         # resident prefix pages and sets its slot's pos absolutely,
         # overwriting the blanket pos+1 the decode bookkeeping applied.
-        @partial(jax.jit, donate_argnums=(3,))
+        @jit(donate=(3,))
         def _mixed_step(params, dec_tokens, pf_tokens, cache, dec_tables,
                         pf_tables, pf_start, pf_n, pf_slot, key, temperature):
-            self.mixed_step_traces += 1     # python side effect: runs per
-            #                                 TRACE, not per call — the
-            #                                 compile-once acceptance gate
             dec_logits, cache = models.decode_step(params, cfg, dec_tokens,
                                                    cache, dec_tables)
             pf_logits, cache = models.prefill_paged_padded(
@@ -141,11 +162,11 @@ class Engine(EngineCore):
             pf_tok = sampler.sample(pf_logits, kp, temperature)
             return dec_tok, pf_tok, cache
 
-        @partial(jax.jit, donate_argnums=(0,))
+        @jit(donate=(0,))
         def _insert(cache, sub, slot):
             return cache_utils.cache_insert(cache, sub, slot, self._axes)
 
-        @jax.jit
+        @jit(donate=())
         def _extract(cache, slot):
             return cache_utils.cache_extract(cache, slot, self._axes)
 
@@ -161,11 +182,15 @@ class Engine(EngineCore):
         # the compiled shape (knob values above the cap are clamped)
         self._mixed_cap = min(sched_cfg.max_batch_tokens,
                               sched_cfg.max_context)
-        self.mixed_step_traces = 0
         if sched_cfg.mixed and self._cache_layout != "paged":
             raise RuntimeError(
                 f"{name}: mixed batching needs the paged cache layout "
                 f"(got {self._cache_layout!r})")
+
+    @property
+    def mixed_step_traces(self) -> int:
+        """Traces of the jitted mixed step: the compile-once gate."""
+        return self.traces["_mixed_step"]
 
     # ----------------------------------------------------------- cache layout
     @property
@@ -228,37 +253,63 @@ class Engine(EngineCore):
         self._key, sub = jax.random.split(self._key)
         return sub
 
+    # --------------------------------------------------------------- tracing
+    def attach_tracer(self, tracer):
+        """Attach ``tracer`` (built on this engine's ``now``) and anchor
+        its clock to the host's, so its spans line up with a profile."""
+        tracer.anchor(f"{self.name}.now")
+        return super().attach_tracer(tracer)
+
+    def _phase(self, name: str, parent=None):
+        tr = self.tracer
+        if tr is None:
+            return NO_PHASE
+        return tr.phase(name, parent, engine=self.name)
+
     # ------------------------------------------------------------------ step
     def step(self) -> StepKind:
-        """Run one scheduler plan synchronously.  Returns the plan kind."""
+        """Run one scheduler plan synchronously.  Returns the plan kind.
+
+        Traced, the ``engine.step`` span carries ``kind``, ``rows``
+        (decode rows advanced), ``chunk`` (prefill tokens computed) and
+        ``traced`` (a jitted function was traced in this step)."""
         if self.paused:
             return StepKind.IDLE
         t_start = time.monotonic()
-        plan = self.scheduler.plan_step()
-        if plan.kind == StepKind.PREFILL:
-            firsts = []
-            for work in plan.prefills:
-                if self._cache_layout == "paged":
-                    # the scheduler's chunk is honored as planned: a
-                    # chunked prefill spans multiple steps (the
-                    # prefill_chunk knob is live on real hardware, not
-                    # just in the sim)
-                    firsts.append(self._run_prefill_paged(work.req,
-                                                          work.chunk))
-                else:
-                    work.chunk = work.req.prompt_len   # ring: one shot
-                    firsts.append(self._run_prefill(work.req))
-            self.apply_prefill(plan.prefills, firsts, self.now())
-        elif plan.kind == StepKind.MIXED:
-            self._run_mixed(plan)
-        elif plan.kind == StepKind.DECODE:
-            live = [r for r in plan.decodes
-                    if self.scheduler.ensure_decode_capacity(r)]
-            if live:
-                toks = self._run_decode(live)
-                self.apply_decode(live, toks, self.now())
-        self.steps += 1
-        self._step_metrics(time.monotonic() - t_start)
+        with self._phase("engine.step") as root:
+            with self._phase("engine.plan", root):
+                plan = self.scheduler.plan_step()
+                live = ([r for r in plan.decodes
+                         if self.scheduler.ensure_decode_capacity(r)]
+                        if plan.kind in (StepKind.DECODE, StepKind.MIXED)
+                        else [])
+            n_traces = sum(self.traces.values()) if root is not None else 0
+            if plan.kind == StepKind.PREFILL:
+                firsts = [self._run_prefill_work(work, root)
+                          for work in plan.prefills]
+            elif plan.kind == StepKind.MIXED:
+                dec_tok, first = self._run_mixed(plan.prefills[0], live, root)
+            elif plan.kind == StepKind.DECODE and live:
+                dec_tok = self._run_decode(live, root)
+            with self._phase("engine.apply", root):
+                if plan.kind == StepKind.PREFILL:
+                    self.apply_prefill(plan.prefills, firsts, self.now())
+                elif plan.kind == StepKind.MIXED:
+                    toks = self._take_tokens(live, dec_tok)
+                    now = self.now()
+                    self.apply_prefill(plan.prefills[:1], [first], now)
+                    if live:
+                        self.apply_decode(live, toks, now)
+                elif plan.kind == StepKind.DECODE and live:
+                    toks = self._take_tokens(live, dec_tok)
+                    self.apply_decode(live, toks, self.now())
+                self.steps += 1
+                self._step_metrics(time.monotonic() - t_start)
+            if root is not None:
+                root.attrs.update(
+                    kind=plan.kind.value, rows=len(live),
+                    chunk=sum(w.chunk for w in plan.prefills),
+                    traced=sum(self.traces.values()) > n_traces)
         return plan.kind
 
     def run_until_idle(self, max_steps: int = 100_000) -> None:
@@ -267,20 +318,43 @@ class Engine(EngineCore):
                 break
             self.step()
 
-    # ---------------------------------------------------------------- prefill
-    def _run_prefill(self, req: Request) -> int:
-        tokens = jnp.asarray(req.prompt_tokens, jnp.int32)[None, :]
-        sub_cache = models.init_cache(self.cfg, 1,
-                                      self.scheduler.cfg.max_context)
-        tok, sub_cache = self._prefill_fn(self.params, tokens, sub_cache,
-                                          self._next_key(),
-                                          jnp.float32(self.temperature))
-        self.cache = self._insert_fn(self.cache, sub_cache,
-                                     jnp.int32(req.slot))
-        self._last_token[req.slot] = int(tok[0])
-        return int(tok[0])
+    def _take_tokens(self, reqs: list[Request], toks: np.ndarray
+                     ) -> list[int]:
+        """Each row's sampled token, kept as its next decode input."""
+        out = []
+        for r in reqs:
+            t = int(toks[r.slot])
+            self._last_token[r.slot] = t
+            out.append(t)
+        return out
 
-    def _run_prefill_paged(self, req: Request, chunk: int):
+    # ---------------------------------------------------------------- prefill
+    def _run_prefill_work(self, work, root=None):
+        if self._cache_layout == "paged":
+            # the scheduler's chunk is honored as planned: a chunked
+            # prefill spans multiple steps (the prefill_chunk knob is
+            # live on real hardware, not just in the sim)
+            return self._run_prefill_paged(work.req, work.chunk, root)
+        work.chunk = work.req.prompt_len        # ring: one shot
+        return self._run_prefill(work.req, root)
+
+    def _run_prefill(self, req: Request, root=None) -> int:
+        with self._phase("engine.inputs", root):
+            tokens = jnp.asarray(req.prompt_tokens, jnp.int32)[None, :]
+            sub_cache = models.init_cache(self.cfg, 1,
+                                          self.scheduler.cfg.max_context)
+            key, temp = self._next_key(), jnp.float32(self.temperature)
+        with self._phase("engine.dispatch", root):
+            tok, sub_cache = self._prefill_fn(self.params, tokens, sub_cache,
+                                              key, temp)
+            self.cache = self._insert_fn(self.cache, sub_cache,
+                                         jnp.int32(req.slot))
+        with self._phase("engine.sync", root):
+            first = int(tok[0])
+        self._last_token[req.slot] = first
+        return first
+
+    def _run_prefill_paged(self, req: Request, chunk: int, root=None):
         """Prefill ``chunk`` uncached prompt tokens straight into the
         shared pool, honoring the scheduler's chunked-prefill plan.
 
@@ -293,80 +367,75 @@ class Engine(EngineCore):
         request keeps prefilling next step)."""
         start = min(req.prefilled, req.prompt_len - 1)
         chunk = min(chunk, req.prompt_len - start)
-        tokens = jnp.asarray(req.prompt_tokens[start:start + chunk],
-                             jnp.int32)[None, :]
-        row = self._block_table_rows([req])[req.slot][None, :]
-        tok, self.cache = self._prefill_paged_fn(
-            self.params, tokens, self.cache, jnp.asarray(row),
-            jnp.full((1,), start, jnp.int32), jnp.int32(req.slot),
-            self._next_key(), jnp.float32(self.temperature))
+        with self._phase("engine.inputs", root):
+            tokens = jnp.asarray(req.prompt_tokens[start:start + chunk],
+                                 jnp.int32)[None, :]
+            row = self._block_table_rows([req])[req.slot][None, :]
+            args = (jnp.asarray(row), jnp.full((1,), start, jnp.int32),
+                    jnp.int32(req.slot), self._next_key(),
+                    jnp.float32(self.temperature))
+        with self._phase("engine.dispatch", root):
+            tok, self.cache = self._prefill_paged_fn(
+                self.params, tokens, self.cache, *args)
         if start + chunk < req.prompt_len:
             return None                     # chunk not final: no token yet
-        self._last_token[req.slot] = int(tok[0])
-        return int(tok[0])
+        with self._phase("engine.sync", root):
+            first = int(tok[0])
+        self._last_token[req.slot] = first
+        return first
 
     # ------------------------------------------------------------------ mixed
-    def _run_mixed(self, plan) -> None:
+    def _run_mixed(self, work, live: list[Request], root=None):
         """One fused step: every live decode slot advances a token while
         one prefill chunk computes — the stall-free continuous-batching
         hot path.  All jit inputs are shape-stable; see ``_mixed_step``
-        in ``__init__``."""
+        in ``__init__``.  Returns (the decode rows' tokens by slot, the
+        chunk's first token or None)."""
         if self._cache_layout != "paged":
             raise RuntimeError(
                 f"{self.name}: mixed batching needs the paged cache "
                 f"layout (current: {self._cache_layout!r})")
-        work = plan.prefills[0]
         req = work.req
-        live = [r for r in plan.decodes
-                if self.scheduler.ensure_decode_capacity(r)]
         cap = self._mixed_cap
         chunk = min(work.chunk, cap)
         work.chunk = chunk                  # bookkeeping sees the clamp
         start = req.prefilled
-        buf = np.zeros((1, cap), np.int32)
-        buf[0, :chunk] = req.prompt_tokens[start:start + chunk]
-        dec_tables = self._block_table_rows(live)
-        pf_row = self._block_table_rows([req])[req.slot][None, :]
-        dec_tok, pf_tok, self.cache = self._mixed_fn(
-            self.params, jnp.asarray(self._last_token[:, None]),
-            jnp.asarray(buf), self.cache, jnp.asarray(dec_tables),
-            jnp.asarray(pf_row), jnp.int32(start), jnp.int32(chunk),
-            jnp.int32(req.slot), self._next_key(),
-            jnp.float32(self.temperature))
-        dec_tok = np.asarray(dec_tok)
-        toks = []
-        for r in live:
-            t = int(dec_tok[r.slot])
-            self._last_token[r.slot] = t
-            toks.append(t)
+        with self._phase("engine.inputs", root):
+            buf = np.zeros((1, cap), np.int32)
+            buf[0, :chunk] = req.prompt_tokens[start:start + chunk]
+            dec_tables = self._block_table_rows(live)
+            pf_row = self._block_table_rows([req])[req.slot][None, :]
+            args = (jnp.asarray(self._last_token[:, None]), jnp.asarray(buf),
+                    self.cache, jnp.asarray(dec_tables), jnp.asarray(pf_row),
+                    jnp.int32(start), jnp.int32(chunk), jnp.int32(req.slot),
+                    self._next_key(), jnp.float32(self.temperature))
+        with self._phase("engine.dispatch", root):
+            dec_tok, pf_tok, self.cache = self._mixed_fn(self.params, *args)
         final = (start + chunk) >= req.prompt_len
-        first = int(pf_tok[0]) if final else None
+        with self._phase("engine.sync", root):
+            dec_tok = np.asarray(dec_tok)
+            first = int(pf_tok[0]) if final else None
         if final:
             self._last_token[req.slot] = first
-        now = self.now()
-        self.apply_prefill([work], [first], now)
-        if live:
-            self.apply_decode(live, toks, now)
+        return dec_tok, first
 
     # ----------------------------------------------------------------- decode
-    def _run_decode(self, reqs: list[Request]) -> list[int]:
-        tokens = jnp.asarray(self._last_token[:, None])
-        if self._cache_layout == "paged":
-            tables = jnp.asarray(self._block_table_rows(reqs))
-            toks, self.cache = self._decode_paged_fn(
-                self.params, tokens, self.cache, tables, self._next_key(),
-                jnp.float32(self.temperature))
-        else:
-            toks, self.cache = self._decode_fn(
-                self.params, tokens, self.cache, self._next_key(),
-                jnp.float32(self.temperature))
-        toks = np.asarray(toks)
-        out = []
-        for r in reqs:
-            t = int(toks[r.slot])
-            self._last_token[r.slot] = t
-            out.append(t)
-        return out
+    def _run_decode(self, reqs: list[Request], root=None) -> np.ndarray:
+        """One decode step over every slot; the sampled tokens by slot."""
+        with self._phase("engine.inputs", root):
+            tokens = jnp.asarray(self._last_token[:, None])
+            tables = (jnp.asarray(self._block_table_rows(reqs))
+                      if self._cache_layout == "paged" else None)
+            key, temp = self._next_key(), jnp.float32(self.temperature)
+        with self._phase("engine.dispatch", root):
+            if tables is not None:
+                toks, self.cache = self._decode_paged_fn(
+                    self.params, tokens, self.cache, tables, key, temp)
+            else:
+                toks, self.cache = self._decode_fn(
+                    self.params, tokens, self.cache, key, temp)
+        with self._phase("engine.sync", root):
+            return np.asarray(toks)
 
     # ------------------------------------------------------------ kv transfer
     def extract_state(self, req: Request):

@@ -167,9 +167,10 @@ class EngineCore(ControlSurface):
             req.arrival_time = self.now()
         self._trace_submit(req)
         self.scheduler.submit(req)
-        self._gauge("queue_len", self.scheduler.queue_len)
-        self._gauge("prefill_queue_tokens",
-                    self.scheduler.prefill_queue_tokens)
+        if self.collector is not None:
+            self._gauge("queue_len", self.scheduler.queue_len)
+            self._gauge("prefill_queue_tokens",
+                        self.scheduler.prefill_queue_tokens)
         self.kick()
 
     def admit_handoff(self, req: Request) -> bool:
@@ -348,6 +349,11 @@ class EngineCore(ControlSurface):
         raise NotImplementedError
 
     # ------------------------------------------------------------- tracing
+    def attach_tracer(self, tracer):
+        """Record this engine's request segments in ``tracer``."""
+        self.tracer = tracer
+        return tracer
+
     # Segment spans tile [arrival, finish] exactly: each lifecycle
     # transition closes the open segment and opens the next at the same
     # timestamp, so the per-request decomposition sums to the measured
@@ -424,14 +430,16 @@ class EngineCore(ControlSurface):
             self.collector.observe(f"{self.name}.{name}", value, self.now())
 
     def _step_metrics(self, duration: float) -> None:
+        self.step_time_total += duration
+        self.mean_step_time = (duration if self.steps <= 1 else
+                               0.9 * self.mean_step_time + 0.1 * duration)
+        if self.collector is None:
+            return          # the gauges below sort and scan: skip unread
         s = self.scheduler
         self._gauge("queue_len", s.queue_len)
         self._gauge("num_running", s.num_running)
         self._gauge("page_util", s.alloc.utilization)
         self._observe("step_time", duration)
-        self.step_time_total += duration
-        self.mean_step_time = (duration if self.steps <= 1 else
-                               0.9 * self.mean_step_time + 0.1 * duration)
         self._gauge("mean_step_time", self.mean_step_time)
         self._gauge("tokens_total", self.tokens_generated)
         self._gauge("itl_p95", self.itl_p95)

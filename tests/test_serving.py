@@ -130,6 +130,32 @@ def test_real_engine_greedy_deterministic():
     assert outs[0] == outs[1]
 
 
+def test_real_engine_gauges_only_with_a_collector(monkeypatch):
+    """With no collector the step skips its gauges (the windowed ITL
+    sort, the waiting-queue scan) but keeps the step-time and token
+    counters; with one, the gauges are published."""
+    cfg, eng = _real_engine()
+
+    def unread(self):
+        raise AssertionError("gauge computed with no collector")
+    monkeypatch.setattr(Engine, "itl_p95", property(unread))
+    monkeypatch.setattr(type(eng.scheduler), "prefill_queue_tokens",
+                        property(unread))
+    p = np.asarray(np.arange(5, 13) % cfg.vocab, np.int32)
+    eng.submit(Request(prompt_len=len(p), max_new_tokens=4,
+                       prompt_tokens=p))
+    eng.run_until_idle()
+    assert eng.tokens_generated == 4 and eng.step_time_total > 0
+    assert eng.mean_step_time > 0
+    monkeypatch.undo()
+    eng.collector = col = Collector()
+    eng.submit(Request(prompt_len=len(p), max_new_tokens=4,
+                       prompt_tokens=p))
+    eng.run_until_idle()
+    assert col._rings[f"{eng.name}.itl_p95"].last() is not None
+    assert col._rings[f"{eng.name}.prefill_queue_tokens"].last() is not None
+
+
 def test_real_engine_kv_extract_inject_parity():
     """Migrating a sequence between engines preserves greedy decoding."""
     cfg, eng1 = _real_engine()

@@ -330,3 +330,196 @@ def test_disagg_handoff_traced_with_kv_chunk_spans():
     # the request's segments still tile its latency across BOTH engines
     for span, sgs, dur in request_decomposition(spans):
         assert abs(sum(sgs.values()) - dur) <= 0.01 * max(dur, 1e-9)
+
+
+# ---------------------------------------------------------------------------
+# Live engine: step-phase spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+PHASES = ("engine.plan", "engine.inputs", "engine.dispatch", "engine.sync",
+          "engine.apply")
+
+
+def _live_engine(layout="paged", mixed=True, chunk=8):
+    import jax
+    from repro import models
+    from repro.serving.engine import Engine
+    cfg = get_config("tiny-agent").replace(dtype="float32")
+    sched = SchedulerConfig(max_slots=3, num_pages=64, max_context=128,
+                            page_size=16, max_batch_tokens=64,
+                            prefill_chunk=chunk, mixed=mixed)
+    eng = Engine(cfg, models.init(cfg, jax.random.key(0)), sched,
+                 name=f"live-{layout}-{int(mixed)}", cache_layout=layout)
+    return cfg, eng
+
+
+def _live_tracer(eng, enabled=True):
+    tr = Tracer(eng.now, name="live.tracer")
+    tr.set_scope(None, 1.0 if enabled else 0.0)
+    eng.attach_tracer(tr)
+    return tr
+
+
+def _serve(eng, lengths, seed=0, max_new=4):
+    import numpy as np
+    rng = np.random.default_rng(seed)
+    reqs = [Request(prompt_len=n, max_new_tokens=max_new,
+                    prompt_tokens=rng.integers(0, 200, n).astype(np.int32))
+            for n in lengths]
+    for r in reqs:
+        eng.submit(r)
+    eng.run_until_idle()
+    assert all(r.state == RequestState.FINISHED for r in reqs)
+    return reqs
+
+
+def _steps(spans):
+    """(engine.step span, its children) in time order."""
+    kids: dict = {}
+    for s in spans:
+        if s.parent_id is not None and s.cat == "step":
+            kids.setdefault(s.parent_id, []).append(s)
+    return [(s, kids.get(s.span_id, [])) for s in spans
+            if s.name == "engine.step"]
+
+
+@pytest.mark.parametrize("layout,mixed", [
+    ("paged", True),          # mixed steps, then decode steps
+    ("paged", False),         # serialized chunked paged prefill
+    ("ring", False),          # one-shot ring prefill
+])
+def test_live_engine_step_phases_tile_each_step(layout, mixed):
+    _, eng = _live_engine(layout, mixed)
+    tr = _live_tracer(eng)
+    plans = []
+    plan_step = eng.scheduler.plan_step
+
+    def recorded():
+        plans.append(plan_step())
+        return plans[-1]
+    eng.scheduler.plan_step = recorded
+    lengths = (35, 21, 40)
+    _serve(eng, lengths)
+    first_wave = len(plans)
+    _serve(eng, lengths, seed=1)          # same shapes: nothing retraces
+    steps = _steps(tr.all_spans())
+    assert len(steps) == len(plans) == eng.steps
+    # the phases tile the steps: what lies between them is the spans'
+    # own entry and exit (tens of microseconds a step)
+    assert sum(k.dur for _, kids in steps for k in kids) >= \
+        0.9 * sum(root.dur for root, _ in steps)
+    kinds_seen = set()
+    for i, ((root, kids), plan) in enumerate(zip(steps, plans)):
+        assert {k.name for k in kids} <= set(PHASES)
+        assert kids[0].name == "engine.plan"
+        assert kids[-1].name == "engine.apply"
+        assert all(root.t0 <= k.t0 and k.t1 <= root.t1 for k in kids)
+        assert root.attrs["kind"] == plan.kind.value
+        assert root.attrs["rows"] == len(plan.decodes)
+        assert root.attrs["chunk"] == sum(w.chunk for w in plan.prefills)
+        if i >= first_wave:
+            assert root.attrs["traced"] is False
+        elif mixed:
+            # each executable traces on its first call only
+            assert root.attrs["traced"] is (plan.kind not in kinds_seen)
+            kinds_seen.add(plan.kind)
+    assert any(r.attrs["traced"] for r, _ in steps)
+    assert {r.attrs["kind"] for r, _ in steps} >= (
+        {"mixed", "decode"} if mixed else {"prefill", "decode"})
+    if mixed:
+        assert eng.mixed_step_traces == 1
+        assert eng.traces["_decode_paged"] == 1
+
+
+def test_phases_cost_nothing_without_an_enabled_tracer(monkeypatch):
+    import jax.profiler
+    built = []
+
+    class Counting(jax.profiler.TraceAnnotation):
+        def __init__(self, *a, **k):
+            built.append(a)
+            super().__init__(*a, **k)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    _, eng = _live_engine()
+    assert eng.tracer is None
+    _serve(eng, (35, 21))
+    tr = _live_tracer(eng, enabled=False)
+    _serve(eng, (35, 21))
+    assert built == [] and tr.spans == [] and tr.spans_total == 0
+    assert tr.phase("engine.step") is tr.phase("engine.plan")  # shared
+    tr.set_scope(None, 1.0)
+    _serve(eng, (35,))
+    assert len(built) == sum(s.cat == "step" for s in tr.spans) > 0
+
+
+def test_live_engine_phases_in_profiler_host_plane(tmp_path):
+    """Under jax.profiler.trace the engine.* events sit in the host plane,
+    nested in time inside their engine.step, and the tracer's anchor
+    puts its spans where the profiler stamped them."""
+    import statistics
+
+    import jax
+    from jax.profiler import ProfileData
+    _, eng = _live_engine()
+    _serve(eng, (35, 21))                 # compile outside the trace
+    tr = _live_tracer(eng)
+    with jax.profiler.trace(str(tmp_path)):
+        _serve(eng, (35, 21), seed=2)
+    pd = ProfileData.from_file(str(sorted(tmp_path.rglob("*.xplane.pb"))[-1]))
+    events = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+              for p in pd.planes if p.name.startswith("/host:")
+              for line in p.lines for e in line.events
+              if e.name.startswith("engine.")]
+    roots = sorted(e for e in events if e[0] == "engine.step")
+    spans = _steps(tr.all_spans())
+    assert len(roots) == len(spans) > 0
+    for name, s, e in events:
+        if name != "engine.step":
+            assert any(a <= s and e <= b for _, a, b in roots), name
+    for (_, a, b), (root, kids) in zip(roots, spans):
+        inside = sorted(n for n, s, e in events
+                        if n != "engine.step" and a <= s and e <= b)
+        assert inside == sorted(k.name for k in kids)
+    env = pd.find_plane_with_name("Task Environment")
+    start = dict(env.stats)["profile_start_time"]
+    gaps = [abs(s + start - (root.t0 + tr.origin_unix_s) * 1e9)
+            for (_, s, _), (root, _) in zip(roots, spans)]
+    assert statistics.median(gaps) < 2e6    # ns; a CPU bound, not the chip's
+    doc = tr.export()
+    assert doc["otherData"]["clock"] == f"{eng.name}.now"
+    assert doc["otherData"]["origin_unix_s"] == tr.origin_unix_s
+
+
+def test_live_engine_request_segments_still_tile_latency():
+    _, eng = _live_engine()
+    tr = _live_tracer(eng)
+    reqs = _serve(eng, (35, 21, 40, 9))
+    decomp = request_decomposition(tr.all_spans())
+    assert len(decomp) == len(reqs)
+    for span, segs, dur in decomp:
+        assert set(segs) <= set(SEGMENTS)
+        assert abs(sum(segs.values()) - dur) <= 0.01 * max(dur, 1e-9)
+
+
+def test_trace_report_leaves_step_spans_out(tmp_path):
+    _, eng = _live_engine()
+    tr = _live_tracer(eng)
+    reqs = _serve(eng, (35, 21, 40))
+    out = tmp_path / "TRACE_live.json"
+    tr.export(out)
+    rpt = _report_tool()
+    doc = rpt.load(out)
+    assert rpt.validate(doc) == []
+    spans = rpt.spans_from(doc)
+    assert any(s.cat == "step" for s in spans)
+    checks = rpt.decomposition_check(spans)
+    assert len(checks) == len(reqs)
+    for _, total, dur in checks:
+        assert abs(total - dur) <= 0.01 * max(dur, 1e-9)
+    for r in reqs:
+        path = rpt.critical_path(spans, r.req_id)
+        assert path and all(s.cat == "request" for s in path)
+    phases = rpt.step_phases(spans)[eng.name]
+    assert phases["engine.step"][0] == eng.steps
+    assert set(phases) == {"engine.step", *PHASES}
+    assert f"{eng.steps} steps" in rpt.report(doc)

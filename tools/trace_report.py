@@ -1,7 +1,9 @@
 #!/usr/bin/env python
 """Trace report — walk an exported ``TRACE_*.json`` (Chrome-trace JSON,
 written by ``Tracer.export``) and print, per task, the workflow's
-critical path with the dominant latency segment at every hop.
+critical path with the dominant latency segment at every hop, and for a
+live engine's step spans (category ``step``) the mean time of each
+phase.  Step spans never enter the request tiling or the critical path.
 
 Works from the exported file alone (stdlib only, no repro import): the
 span tree is rebuilt from the ``args.span_id``/``args.parent_id`` the
@@ -191,6 +193,21 @@ def decomposition_check(spans: list[SpanView]) -> list[tuple]:
     return out
 
 
+def step_phases(spans: list[SpanView]) -> dict[str, dict[str, tuple]]:
+    """Per engine, per step-phase name: (count, mean seconds) of its
+    ``step`` spans (``engine.step`` and the phases that tile it)."""
+    acc: dict[str, dict[str, list]] = {}
+    for s in spans:
+        if s.cat != "step":
+            continue
+        eng = str(s.args.get("engine", s.trace_id))
+        n_t = acc.setdefault(eng, {}).setdefault(s.name, [0, 0.0])
+        n_t[0] += 1
+        n_t[1] += s.dur
+    return {e: {k: (n, t / n) for k, (n, t) in ph.items()}
+            for e, ph in acc.items()}
+
+
 # ---------------------------------------------------------------------------
 # report
 # ---------------------------------------------------------------------------
@@ -222,6 +239,12 @@ def report(doc, limit: int = 8) -> str:
         acts = task.args.get("actions") or []
         for a in acts:
             lines.append(f"    ! control: {a}")
+    for eng, phases in sorted(step_phases(spans).items()):
+        lines.append("")
+        lines.append(f"{eng}: {phases.get('engine.step', (0,))[0]} steps; "
+                     "mean ms " + ", ".join(
+                         f"{k} {t * 1e3:.3f}" for k, (_, t) in
+                         sorted(phases.items())))
     checks = decomposition_check(spans)
     if checks:
         worst = max(abs(tot - dur) / max(dur, _EPS)
