@@ -68,8 +68,8 @@ def paged_decode_attention_row(rep, b, h, hkv, dh, page, per_seq, shared):
     n = shared + b * (per_seq - shared)
     ks = jax.random.split(jax.random.key(5), 3)
     q = jax.random.normal(ks[0], (b, 1, h, dh), jnp.float32)
-    k_pages = jax.random.normal(ks[1], (n, page, hkv, dh), jnp.float32)
-    v_pages = jax.random.normal(ks[2], (n, page, hkv, dh), jnp.float32)
+    k_pages = jax.random.normal(ks[1], (n, hkv, dh, page), jnp.float32)
+    v_pages = jax.random.normal(ks[2], (n, hkv, dh, page), jnp.float32)
     rows, nxt = [], shared
     for _ in range(b):
         rows.append(list(range(shared))

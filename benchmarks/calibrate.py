@@ -75,8 +75,8 @@ def kernel_correctness(rep: Report) -> None:
     n = 2 + b * (per_seq - 2)            # 2 shared + private pages
     ks = jax.random.split(jax.random.key(7), 3)
     q = jax.random.normal(ks[0], (b, 1, h, dh), jnp.float32)
-    k_pages = jax.random.normal(ks[1], (n, page, hkv, dh), jnp.float32)
-    v_pages = jax.random.normal(ks[2], (n, page, hkv, dh), jnp.float32)
+    k_pages = jax.random.normal(ks[1], (n, hkv, dh, page), jnp.float32)
+    v_pages = jax.random.normal(ks[2], (n, hkv, dh, page), jnp.float32)
     rows, nxt = [], 2
     for _ in range(b):                   # same physical prefix ids per row
         rows.append([0, 1] + list(range(nxt, nxt + per_seq - 2)))

@@ -96,16 +96,16 @@ def _layer_kv_bytes(cfg, pages: int) -> int:
 def pool_footprint(cfg, weight_bytes: int, pages: int) -> int:
     """HBM a paged decode step needs at ``pages``: the weights, the pool
     twice (the donated pool, and the fresh copy the layer scan builds as
-    its stacked output), and four one-layer K+V buffers (the layer's
-    slice, its written update, and the kernel-layout transpose of K and
-    of V)."""
-    return weight_bytes + (2 * cfg.n_layers + 4) * _layer_kv_bytes(cfg, pages)
+    its stacked output), and two one-layer K+V buffers (the layer's
+    slice and its written update; the kernel reads the pool as
+    stored)."""
+    return weight_bytes + (2 * cfg.n_layers + 2) * _layer_kv_bytes(cfg, pages)
 
 
 def pool_pages(cfg, weight_bytes: int, hbm_bytes: int) -> int:
     """Largest pool whose ``pool_footprint`` fits ``hbm_bytes`` less
     ``HBM_MARGIN``."""
-    per_page = _layer_kv_bytes(cfg, 0) * (2 * cfg.n_layers + 4)
+    per_page = _layer_kv_bytes(cfg, 0) * (2 * cfg.n_layers + 2)
     return (hbm_bytes - HBM_MARGIN - weight_bytes) // per_page - 1
 
 

@@ -100,26 +100,21 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
                            window=-1, interpret=None):
     """Paged decode attention over a shared KV page pool.
 
-    Model layout: q (B, 1, H, dh); k_pages/v_pages (N_pages, page, Hkv,
-    dh) — the allocator-natural pool layout (a real engine would store
-    pages in the kernel's (Hkv, N, page, dh) layout and skip the
-    transpose); block_tables (B, P) int32 physical page ids in logical
-    order, -1 = unmapped tail; ctx_lens (B,) int32 valid cached tokens.
-    Returns (B, 1, H, dh).
+    Model layout: q (B, 1, H, dh); k_pages/v_pages (N_pages, Hkv, dh,
+    page) — the pool as stored, tokens along the minor axis, which the
+    kernel reads in place (only live pages, never the whole pool);
+    block_tables (B, P) int32 physical page ids in logical order, -1 =
+    unmapped tail; ctx_lens (B,) int32 valid cached tokens.  Returns
+    (B, 1, H, dh).
     """
     interpret = interpret_mode(interpret)
     b, _, h, dh = q.shape
-    hkv = k_pages.shape[2]
-    g = h // hkv
-    qg = q.reshape(b, hkv, g, dh)
-    qg = _pad_to(_pad_to(qg, 2, SUBLANE), 3, LANE)
-    kt = _pad_to(jnp.moveaxis(k_pages, 2, 0), 3, LANE)  # (Hkv, N, page, dh')
-    vt = _pad_to(jnp.moveaxis(v_pages, 2, 0), 3, LANE)
-    # scale uses the padded dh; rescale q to compensate
-    qg = qg * (jnp.sqrt(qg.shape[-1] / dh).astype(qg.dtype))
-    out = _pdec.paged_decode_attention(qg, kt, vt, block_tables, ctx_lens,
-                                       window=window, interpret=interpret)
-    return out[:, :, :g, :dh].reshape(b, 1, h, dh)
+    hkv = k_pages.shape[1]
+    out = _pdec.paged_decode_attention(q.reshape(b, hkv, h // hkv, dh),
+                                       k_pages, v_pages, block_tables,
+                                       ctx_lens, window=window,
+                                       interpret=interpret)
+    return out.reshape(b, 1, h, dh)
 
 
 def grouped_matmul(x, w, counts, *, interpret=None):

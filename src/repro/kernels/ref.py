@@ -47,14 +47,15 @@ def decode_attention_ref(q, k, v, kpos, q_pos, *, window=-1):
 def paged_decode_attention_ref(q, k_pages, v_pages, block_tables, ctx_lens,
                                *, window=-1):
     """Gather-then-attend oracle for the paged kernel.  q: (B,Hkv,G,dh);
-    k_pages/v_pages: (N,page,Hkv,dh); block_tables: (B,P) int32 (-1 =
+    k_pages/v_pages: (N,Hkv,dh,page); block_tables: (B,P) int32 (-1 =
     unmapped); ctx_lens: (B,)."""
     b = q.shape[0]
-    page = k_pages.shape[1]
+    page = k_pages.shape[-1]
     t = block_tables.shape[1] * page
     ids = jnp.maximum(block_tables, 0)                    # (B, P)
-    k = k_pages[ids].reshape(b, t, *k_pages.shape[2:])    # (B, T, Hkv, dh)
-    v = v_pages[ids].reshape(b, t, *v_pages.shape[2:])
+    # (B, P, Hkv, dh, page) -> (B, T, Hkv, dh)
+    k = jnp.moveaxis(k_pages[ids], 4, 2).reshape(b, t, *k_pages.shape[1:3])
+    v = jnp.moveaxis(v_pages[ids], 4, 2).reshape(b, t, *v_pages.shape[1:3])
     kpos = jnp.broadcast_to(jnp.arange(t)[None], (b, t))
     kpos = jnp.where(kpos < ctx_lens[:, None], kpos, -1)
     qpos = (ctx_lens - 1)[:, None]

@@ -240,9 +240,12 @@ def attention_cached(q: jax.Array, cache: KVCache, q_pos: jax.Array, *,
 
 
 class PagedKVCache(NamedTuple):
-    """Shared KV page pool for one layer: ``(num_pages + 1, page, Hkv,
-    dh)``.  Physical page ids come from ``serving.kv_cache.PageAllocator``
-    — page ``i`` of the pool IS allocator page ``i``, so the scheduling
+    """Shared KV page pool for one layer: ``(num_pages + 1, Hkv, dh,
+    page)``, tokens along the minor axis, so a page is one contiguous
+    slab, a head size such as 120 tiles without lane padding, and the
+    Pallas kernel reads the pool as stored.  Physical page ids come from
+    ``serving.kv_cache.PageAllocator`` — page ``i`` of the pool IS
+    allocator page ``i``, so the scheduling
     plane's accounting and the attention memory layout are one structure,
     and sequences acquiring a shared prefix block attend through the
     *same* physical pages with zero KV copies.
@@ -252,15 +255,15 @@ class PagedKVCache(NamedTuple):
     there instead of corrupting page 0.  Reads clamp -1 to page 0 and
     mask by position, matching the Pallas kernel's contract."""
 
-    k: jax.Array       # (num_pages + 1, page, Hkv, dh)
-    v: jax.Array       # (num_pages + 1, page, Hkv, dh)
+    k: jax.Array       # (num_pages + 1, Hkv, dh, page)
+    v: jax.Array       # (num_pages + 1, Hkv, dh, page)
 
 
 def init_paged_kv_cache(num_pages: int, page: int, hkv: int, dh: int,
                         dtype) -> PagedKVCache:
     return PagedKVCache(
-        k=jnp.zeros((num_pages + 1, page, hkv, dh), dtype),
-        v=jnp.zeros((num_pages + 1, page, hkv, dh), dtype),
+        k=jnp.zeros((num_pages + 1, hkv, dh, page), dtype),
+        v=jnp.zeros((num_pages + 1, hkv, dh, page), dtype),
     )
 
 
@@ -268,7 +271,7 @@ def _phys_slots(cache: PagedKVCache, tables: jax.Array,
                 pos: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Map absolute token positions (B,S) through block tables (B,P) to
     (physical page, in-page slot); unmapped positions hit the sink."""
-    page = cache.k.shape[1]
+    page = cache.k.shape[-1]
     p_max = tables.shape[1]
     sink = cache.k.shape[0] - 1
     logical = pos // page
@@ -278,26 +281,55 @@ def _phys_slots(cache: PagedKVCache, tables: jax.Array,
     return jnp.where(bad, sink, phys), pos % page
 
 
-def paged_cache_write(cache: PagedKVCache, k_new: jax.Array,
-                      v_new: jax.Array, start_pos: jax.Array,
-                      tables: jax.Array) -> PagedKVCache:
-    """Append S_new tokens at absolute positions start_pos..+S_new
-    through per-sequence block tables (B, P) of physical page ids.
-    Live rows own their mapped pages exclusively, so scatters never
-    collide; -1 rows (inactive slots) land in the sink page."""
-    b, s_new = k_new.shape[:2]
-    pos = start_pos[:, None] + jnp.arange(s_new)[None, :]         # (B, S)
-    return paged_cache_write_at(cache, k_new, v_new, pos, tables)
+def paged_cache_fill(cache: PagedKVCache, k_new: jax.Array,
+                     v_new: jax.Array, pos: jax.Array,
+                     tables: jax.Array) -> PagedKVCache:
+    """Write each row's tokens at consecutive positions ``pos[:, 0] +
+    s`` (a -1 tail marks padding, a -1 row writes nothing) — the
+    engine's write path.  Whole pages are gathered, filled and scattered
+    back, so no write indexes the pool's minor (token) axis and the
+    compiler keeps the pool as stored, with no copy of it around the
+    write.  Live rows own their mapped pages exclusively, so page
+    writes never collide; pages no token lands in go to the sink."""
+    b, s = pos.shape
+    hkv, dh, page = cache.k.shape[1:]
+    p_max = tables.shape[1]
+    sink = cache.k.shape[0] - 1
+    n_pg = (s - 2) // page + 2          # pages s consecutive tokens span
+    start = pos[:, :1]
+    logical = jnp.maximum(start, 0) // page + jnp.arange(n_pg)  # (B, n_pg)
+    # the token that lands in slot t of page j, and whether it is real
+    tok = (logical[:, :, None] * page + jnp.arange(page)
+           - start[:, :, None]).reshape(b, -1)            # (B, n_pg·page)
+    src = jnp.clip(tok, 0, s - 1)
+    real = ((tok >= 0) & (tok < s)
+            & (jnp.take_along_axis(pos, src, axis=1) >= 0))
+    real = real.reshape(b, n_pg, 1, 1, page)
+    phys = jnp.take_along_axis(tables, jnp.minimum(logical, p_max - 1),
+                               axis=1)
+    live = (logical < p_max) & (phys >= 0) & real.any(axis=(2, 3, 4))
+    dest = jnp.where(live, phys, sink)
+
+    def fill(pool, new):
+        slab = jnp.take_along_axis(new, src[:, :, None, None], axis=1)
+        slab = jnp.moveaxis(slab.reshape(b, n_pg, page, hkv, dh), 2, -1)
+        return pool.at[dest].set(
+            jnp.where(real, slab.astype(pool.dtype), pool[dest]))
+
+    return PagedKVCache(fill(cache.k, k_new), fill(cache.v, v_new))
 
 
 def paged_cache_write_at(cache: PagedKVCache, k_new: jax.Array,
                          v_new: jax.Array, pos: jax.Array,
                          tables: jax.Array) -> PagedKVCache:
     """Scatter tokens at explicit absolute positions (B, S); negative
-    positions (unwritten ring slots during KV injection) hit the sink."""
+    positions (unwritten ring slots during KV injection) hit the sink.
+    Tokens are scattered one by one along the pool's token axis, which
+    the compiler may serve with a relaid copy of the pool: the engine's
+    step writes through ``paged_cache_fill``."""
     phys, slot = _phys_slots(cache, tables, pos)
-    k = cache.k.at[phys, slot].set(k_new)
-    v = cache.v.at[phys, slot].set(v_new)
+    k = cache.k.at[phys, :, :, slot].set(k_new)
+    v = cache.v.at[phys, :, :, slot].set(v_new)
     return PagedKVCache(k, v)
 
 
@@ -309,10 +341,14 @@ def paged_view(cache: PagedKVCache, tables: jax.Array) -> KVCache:
     absolute position (logical index) for mapped pages, -1 for the
     unmapped tail."""
     b, p_max = tables.shape
-    page, hkv, dh = cache.k.shape[1:]
+    hkv, dh, page = cache.k.shape[1:]
     phys = jnp.maximum(tables, 0)
-    kg = cache.k[phys].reshape(b, p_max * page, hkv, dh)
-    vg = cache.v[phys].reshape(b, p_max * page, hkv, dh)
+
+    def gather(pool):                   # (B, P, Hkv, dh, page) -> tokens
+        return jnp.moveaxis(pool[phys], 4, 2).reshape(b, p_max * page,
+                                                      hkv, dh)
+
+    kg, vg = gather(cache.k), gather(cache.v)
     t = p_max * page
     kpos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32)[None], (b, t))
     kpos = jnp.where(jnp.repeat(tables >= 0, page, axis=1), kpos, -1)
@@ -333,12 +369,12 @@ def self_attention_paged(params: dict, x: jax.Array, cache: PagedKVCache,
     the interpret-parity reference the tests pin the kernel against."""
     q, k, v = qkv_project(params, x, cfg, positions)
     pos1 = _pos1d(positions)
-    # write through the explicit per-token positions (identical to the
-    # consecutive-from-start form for ordinary prefill/decode, since
-    # positions ARE consecutive there) so a padded mixed-step chunk can
-    # mark its tail -1: those writes route to the sink page instead of
-    # scribbling past the valid frontier of the sequence's pages
-    cache = paged_cache_write_at(cache, k, v, pos1, tables)
+    # write through the explicit per-token positions (consecutive from
+    # each row's first for prefill and decode) so a padded mixed-step
+    # chunk can mark its tail -1: those writes route to the sink page
+    # instead of scribbling past the valid frontier of the sequence's
+    # pages
+    cache = paged_cache_fill(cache, k, v, pos1, tables)
     sq = q.shape[1]
     if cfg.use_pallas and sq == 1:
         from repro.kernels import ops

@@ -508,7 +508,7 @@ def _pool_to_ring(cfg, pkv, tables, ctx: int, max_context: int):
     from repro.models import attention as attn
     view = attn.paged_view(pkv, tables)                  # (1, P*page, ...)
     size = max_context
-    ring = attn.init_kv_cache(1, size, pkv.k.shape[-2], pkv.k.shape[-1],
+    ring = attn.init_kv_cache(1, size, pkv.k.shape[-3], pkv.k.shape[-2],
                               pkv.k.dtype)
     if ctx <= 0:
         return ring

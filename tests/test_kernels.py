@@ -1,5 +1,7 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps, interpret=True."""
+import contextlib
 import importlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -124,30 +126,60 @@ def test_decode_attention_tail_not_truncated():
 # paged decode attention (block-table indirection over a KV page pool)
 # ---------------------------------------------------------------------------
 
-def _paged_case(b, hkv, g, dh, page, per_seq, shared=0, seed=9):
+def _paged_case(b, hkv, g, dh, page, per_seq, shared=0, seed=9,
+                shuffled=False):
     """Pool + block tables: ``shared`` leading physical pages appear in
-    every row (a cached prefix), the rest are per-sequence private."""
+    every row (a cached prefix), the rest are per-sequence private;
+    ``shuffled`` hands the physical pages out in a random order."""
     n = shared + b * (per_seq - shared)
     ks = jax.random.split(jax.random.key(seed), 3)
     q = jax.random.normal(ks[0], (b, 1, hkv * g, dh))
-    k_pages = jax.random.normal(ks[1], (n, page, hkv, dh))
-    v_pages = jax.random.normal(ks[2], (n, page, hkv, dh))
+    k_pages = jax.random.normal(ks[1], (n, hkv, dh, page))
+    v_pages = jax.random.normal(ks[2], (n, hkv, dh, page))
     rows, nxt = [], shared
     for _ in range(b):
         rows.append(list(range(shared))
                     + list(range(nxt, nxt + per_seq - shared)))
         nxt += per_seq - shared
-    return q, k_pages, v_pages, jnp.asarray(rows, jnp.int32)
+    bt = np.asarray(rows, np.int32)
+    if shuffled:
+        bt = np.random.default_rng(seed).permutation(n)[bt]
+    return q, k_pages, v_pages, jnp.asarray(bt, jnp.int32)
+
+
+@contextlib.contextmanager
+def _pages_per_block(n, k_pages):
+    """Make the kernel take ``n`` pages a grid step at these shapes (its
+    budget otherwise fits every page of a test's small tables in one)."""
+    pdec = importlib.import_module("repro.kernels.paged_decode_attention")
+    was = pdec.BLOCK_BYTES
+    pdec.BLOCK_BYTES = n * pdec.slab_bytes(*k_pages.shape[1:],
+                                           k_pages.dtype.itemsize)
+    pdec.paged_decode_attention.clear_cache()
+    try:
+        yield
+    finally:
+        pdec.BLOCK_BYTES = was
+        pdec.paged_decode_attention.clear_cache()
 
 
 def _check_paged(q, k_pages, v_pages, bt, ctx, window=-1):
+    """Kernel against the gather-then-attend oracle (zeros for an empty
+    row), with the pages a grid step takes at these shapes and with two
+    pages a step, so rows span several blocks, most with a partial last
+    one."""
     b, _, h, dh = q.shape
-    hkv = k_pages.shape[2]
-    out = ops.paged_decode_attention(q, k_pages, v_pages, bt, ctx,
-                                     window=window, interpret=True)
+    hkv = k_pages.shape[1]
     want = ref.paged_decode_attention_ref(q.reshape(b, hkv, h // hkv, dh),
                                           k_pages, v_pages, bt, ctx,
                                           window=window)
+    want = jnp.where((ctx > 0)[:, None, None, None], want, 0)
+    out = ops.paged_decode_attention(q, k_pages, v_pages, bt, ctx,
+                                     window=window, interpret=True)
+    _assert_close(out.reshape(b, hkv, h // hkv, dh), want, q.dtype)
+    with _pages_per_block(2, k_pages):
+        out = ops.paged_decode_attention(q, k_pages, v_pages, bt, ctx,
+                                         window=window, interpret=True)
     _assert_close(out.reshape(b, hkv, h // hkv, dh), want, q.dtype)
 
 
@@ -155,6 +187,8 @@ def _check_paged(q, k_pages, v_pages, bt, ctx, window=-1):
     (2, 2, 4, 64, 16, 4),       # GQA
     (1, 1, 1, 128, 32, 3),      # MQA, single row, wide head
     (3, 4, 2, 32, 16, 5),
+    (2, 2, 6, 128, 16, 7),      # several blocks, a partial last one
+    (2, 8, 4, 120, 16, 3),      # h2o-danube-3-4b's heads: Hkv 8, G 4, dh 120
 ])
 @pytest.mark.parametrize("aligned", [True, False])
 def test_paged_decode_attention_sweep(b, hkv, g, dh, page, per_seq,
@@ -163,6 +197,19 @@ def test_paged_decode_attention_sweep(b, hkv, g, dh, page, per_seq,
     full = per_seq * page
     ctx = jnp.full((b,), full, jnp.int32) if aligned else \
         jnp.asarray([full - 1 - 7 * i for i in range(b)], jnp.int32)
+    _check_paged(q, kp, vp, bt, ctx)
+
+
+@pytest.mark.parametrize("lens,shuffled", [
+    ([1, 1, 1], False),                 # one cached token
+    ([5 * 16, 5 * 16 - 1, 1], False),   # the last position of P pages
+    ([40, 0, 77], False),               # an empty row between live rows
+    ([80, 33, 61], True),               # physical pages out of order
+])
+def test_paged_decode_attention_contexts(lens, shuffled):
+    q, kp, vp, bt = _paged_case(3, 2, 2, 64, 16, per_seq=5,
+                                shuffled=shuffled)
+    ctx = jnp.asarray(lens, jnp.int32)
     _check_paged(q, kp, vp, bt, ctx)
 
 
@@ -191,7 +238,9 @@ def test_paged_decode_attention_unmapped_tail():
     _check_paged(q, kp, vp, bt, ctx)
 
 
-@pytest.mark.parametrize("window", [24, 64])
+# 40: the window's first token lies inside a page, and with two pages a
+# step its first page starts a block the row's first page does not
+@pytest.mark.parametrize("window", [24, 64, 40])
 def test_paged_decode_attention_window(window):
     b, hkv, g, dh, page = 2, 2, 4, 64, 16
     q, kp, vp, bt = _paged_case(b, hkv, g, dh, page, per_seq=5)
@@ -205,6 +254,27 @@ def test_paged_decode_attention_bf16():
     ctx = jnp.asarray([4 * page, 3 * page - 6], jnp.int32)
     q, kp, vp = (x.astype(jnp.bfloat16) for x in (q, kp, vp))
     _check_paged(q, kp, vp, bt, ctx)
+
+
+def test_paged_decode_attention_reads_the_pool_as_stored():
+    """The wrapper hands the pool to the kernel as stored: no transpose
+    or pad of a pool-sized operand, whose cost would follow the pool and
+    not the live context, at either head shape."""
+    for hkv, g, dh in ((2, 6, 128), (8, 4, 120)):
+        n, page, b, p = 300, 16, 4, 8
+        pool = jax.ShapeDtypeStruct((n, hkv, dh, page), jnp.bfloat16)
+        text = jax.jit(ops.paged_decode_attention, static_argnames=(
+            "window", "interpret")).lower(
+            jax.ShapeDtypeStruct((b, 1, hkv * g, dh), jnp.bfloat16),
+            pool, pool, jax.ShapeDtypeStruct((b, p), jnp.int32),
+            jax.ShapeDtypeStruct((b,), jnp.int32),
+            interpret=True).as_text()
+        moved = [line for line in text.splitlines()
+                 if re.search(r"stablehlo\.(transpose|pad)\b", line)
+                 and any(np.prod([int(d) for d in t.split("x")[:-1]])
+                         >= n * page * hkv * dh
+                         for t in re.findall(r"tensor<([0-9x]+x\w+)>", line))]
+        assert not moved, moved
 
 
 # ---------------------------------------------------------------------------

@@ -254,14 +254,15 @@ def test_paged_attention_matches_contiguous_through_allocator():
 
     # scatter into the physical pool exactly where the tables point
     n_pool = a.num_pages
-    k_pages = jnp.zeros((n_pool, page, hkv, dh))
-    v_pages = jnp.zeros((n_pool, page, hkv, dh))
+    k_pages = jnp.zeros((n_pool, hkv, dh, page))       # tokens minor
+    v_pages = jnp.zeros((n_pool, hkv, dh, page))
     for i, row in enumerate(tables):
         for j, pid in enumerate(row):
             if pid < 0:
                 continue
-            k_pages = k_pages.at[pid].set(k_seq[i, j * page:(j + 1) * page])
-            v_pages = v_pages.at[pid].set(v_seq[i, j * page:(j + 1) * page])
+            span = slice(j * page, (j + 1) * page)
+            k_pages = k_pages.at[pid].set(jnp.moveaxis(k_seq[i, span], 0, -1))
+            v_pages = v_pages.at[pid].set(jnp.moveaxis(v_seq[i, span], 0, -1))
 
     q = jax.random.normal(jax.random.key(4), (b, 1, h, dh))
     out_paged = ops.paged_decode_attention(

@@ -154,6 +154,38 @@ def test_live_engine_executes_pallas_kernel(monkeypatch):
 # Zero-copy shared prefixes
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("s,seed", [(1, 0), (1, 1), (7, 2), (16, 3),
+                                    (17, 4), (40, 5)])
+def test_page_fill_matches_token_scatter(s, seed):
+    """The engine's page-granular write (``paged_cache_fill``: gather
+    whole pages, fill, scatter back) leaves every real page as the plain
+    per-token scatter does: decode (S = 1) and chunks spanning pages,
+    with unmapped table entries, positions past the table, -1 tails and
+    an empty row."""
+    from repro.models import attention as attn
+    rng = np.random.default_rng(seed)
+    b, n, p_max, hkv, dh = 4, 30, 5, 2, 3
+    pool = attn.PagedKVCache(
+        *(jnp.asarray(rng.normal(size=(n + 1, hkv, dh, PAGE)), jnp.float32)
+          for _ in range(2)))
+    tables = rng.permutation(n)[:b * p_max].reshape(b, p_max)
+    tables = np.where(rng.random((b, p_max)) < 0.2, -1, tables)
+    start = rng.integers(0, p_max * PAGE, size=b)
+    start[0] = -1                                       # an empty row
+    n_real = rng.integers(0, s + 1, size=b)
+    n_real[1] = s
+    pos = np.where(np.arange(s) < n_real[:, None],
+                   start[:, None] + np.arange(s), -1)
+    pos[start < 0] = -1
+    k_new = jnp.asarray(rng.normal(size=(b, s, hkv, dh)), jnp.float32)
+    args = (k_new, -k_new, jnp.asarray(pos, jnp.int32),
+            jnp.asarray(tables, jnp.int32))
+    got = attn.paged_cache_fill(pool, *args)
+    want = attn.paged_cache_write_at(pool, *args)
+    for g, w in zip(got, want):                 # the sink page may differ
+        np.testing.assert_array_equal(np.asarray(g[:n]), np.asarray(w[:n]))
+
+
 def test_shared_prefix_zero_copy_admission():
     cfg = BASE.replace(use_pallas=True)
     params = _params(cfg)
